@@ -18,6 +18,7 @@ import sys
 import time
 
 from benchmarks.common import BenchReport, set_active_report
+from repro.common.compile_cache import enable_compile_cache
 
 from benchmarks import (
     cascade_bench,
@@ -52,6 +53,7 @@ OUT_DIR = os.environ.get("REPRO_BENCH_OUT", "reports/bench")
 
 
 def main() -> None:
+    enable_compile_cache()
     selected = sys.argv[1:] or list(SUITES)
     print("name,us_per_call,derived")
     failures = []

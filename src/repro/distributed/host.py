@@ -237,6 +237,9 @@ def main(argv: Optional[list] = None) -> int:
     serve_argv = json.loads(a.serve_argv)
     if not isinstance(serve_argv, list):
         ap.error("--serve-argv must be a JSON list of strings")
+    from repro.common.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     return run_follower(a.wid, a.port, [str(s) for s in serve_argv],
                         host=a.host)
 

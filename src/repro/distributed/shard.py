@@ -6,6 +6,8 @@ module splits pool ownership across the worker fleet:
 
   * :func:`owner_of` — deterministic member -> worker placement
     (round-robin by index, stable under worker count);
+  * :func:`place_pool` — in one process, put each member's parameters
+    on its owner's device (the in-process plane's chip placement);
   * :func:`shard_pool` — on a worker process, lay out the *owned*
     members' parameters with the repo's per-config mesh sharding specs
     (:func:`repro.launch.sharding.param_shardings` over a
@@ -42,6 +44,26 @@ def owner_of(member_idx: int, n_workers: int) -> int:
 def owned_members(wid: int, n_members: int, n_workers: int) -> List[int]:
     return [mi for mi in range(n_members)
             if owner_of(mi, n_workers) == int(wid)]
+
+
+def place_pool(pool, n_workers: int, devices=None) -> list:
+    """Put each member's parameters on its owning worker's device.
+
+    In one process, worker ``w`` runs on ``devices[w % len(devices)]``
+    (default: every local device), so on a host with a chip per worker
+    each member generates on its owner's chip; with one device everything
+    stays there. Returns the device of each member, in pool order.
+    """
+    import jax
+
+    devices = list(jax.local_devices() if devices is None else devices)
+    placed = []
+    for mi, member in enumerate(pool):
+        dev = devices[owner_of(mi, n_workers) % len(devices)]
+        if member.params is not None:
+            member.params = jax.device_put(member.params, dev)
+        placed.append(dev)
+    return placed
 
 
 def shard_pool(pool, wid: int, n_workers: int, *, mesh=None,

@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handle padding to TPU tile granularity (128 lanes), interpret-mode fallback
-on CPU (this container), and un-padding of results. The rest of the codebase
-calls only these entry points.
+Handle padding to TPU tile granularity (128 lanes), interpret mode on the
+CPU, and un-padding of results. The rest of the codebase calls only these
+entry points.
 
 Profiling: :func:`set_kernel_profiler` installs a
 :class:`repro.obs.profiling.KernelProfiler` (or anything with a compatible
@@ -39,8 +39,18 @@ def get_kernel_profiler():
     return _PROFILER
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret mode on the CPU (the tests' platform), the compiled
+    kernel on TPU; any other platform is an error, never a silent
+    interpreter run."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels target TPU; refusing to run them in interpret "
+        f"mode on platform {platform!r}")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -52,10 +62,13 @@ def pool_projections(wk, wv, m_emb):
 
     Per-pool constants at serving time: compute once when the pool is
     (re)built and reuse across every score batch via
-    :func:`router_xattn_pool`.
+    :func:`router_xattn_pool`. Contracted in full float32, as the kernel
+    is: XLA's default on TPU rounds the operands to bf16, which moved the
+    kernel's scores by 5e-3 of their scale on a v5e.
     """
-    kt = m_emb.astype(jnp.float32) @ wk.astype(jnp.float32)
-    vt = m_emb.astype(jnp.float32) @ wv.astype(jnp.float32)
+    m, f32 = m_emb.astype(jnp.float32), jax.lax.Precision.HIGHEST
+    kt = jnp.matmul(m, wk.astype(jnp.float32), precision=f32)
+    vt = jnp.matmul(m, wv.astype(jnp.float32), precision=f32)
     return kt, vt
 
 
@@ -94,7 +107,7 @@ def router_xattn(
     kernel (they are per-pool constants at serving time).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     kt, vt = pool_projections(wk, wv, m_emb)
     return _xattn_padded(q, wq, kt, vt, wo, bo,
                          block_b=block_b, interpret=interpret)
@@ -105,7 +118,7 @@ def _router_xattn_pool_jit(
     q, wq, kt, vt, wo, bo, *, block_b: int = 256, interpret: bool = None
 ):
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     return _xattn_padded(q, wq, kt, vt, wo, bo,
                          block_b=block_b, interpret=interpret)
 
@@ -134,7 +147,7 @@ def _pairwise_l2_jit(
     x, c, *, block_n: int = 256, block_k: int = 256, interpret: bool = None
 ):
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     n, d = x.shape
     k = c.shape[0]
     block_n = min(block_n, _round_up(n, 8))

@@ -21,7 +21,9 @@ def _pairwise_l2_kernel(x_ref, c_ref, out_ref):
     c = c_ref[...].astype(jnp.float32)            # (bk, d)
     x2 = jnp.sum(x * x, axis=1, keepdims=True)    # (bn, 1)
     c2 = jnp.sum(c * c, axis=1)                   # (bk,)
-    cross = jnp.dot(x, c.T, preferred_element_type=jnp.float32)
+    # Full float32 contraction: Mosaic's default rounds to bf16.
+    cross = jnp.dot(x, c.T, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
     d2 = x2 - 2.0 * cross + c2[None, :]
     out_ref[...] = jnp.maximum(d2, 0.0).astype(out_ref.dtype)
 

@@ -29,6 +29,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANE = 128
+# Contract in full float32. Mosaic's default rounds f32 operands to bf16,
+# which moved the scores by 4e-2 of their scale on a v5e against the
+# float32 reference; the matmuls here are far too small for the extra
+# MXU passes to matter.
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _router_xattn_kernel(
@@ -45,11 +50,13 @@ def _router_xattn_kernel(
 ):
     q = q_ref[...].astype(jnp.float32)
     wq = wq_ref[...].astype(jnp.float32)
-    qp = jnp.dot(q, wq, preferred_element_type=jnp.float32)       # (b, d_pad)
+    qp = jnp.dot(q, wq, preferred_element_type=jnp.float32,
+                 precision=_F32)                                  # (b, d_pad)
 
     kt = kt_ref[...].astype(jnp.float32)                          # (K, d_pad)
     scale = 1.0 / math.sqrt(d_latent)
-    logits = jnp.dot(qp, kt.T, preferred_element_type=jnp.float32) * scale
+    logits = jnp.dot(qp, kt.T, preferred_element_type=jnp.float32,
+                     precision=_F32) * scale
 
     kmask = kmask_ref[0, :]                                       # (k_pad,)
     logits = jnp.where(kmask[None, :] > 0, logits, -1e30)
@@ -58,10 +65,12 @@ def _router_xattn_kernel(
     alpha = e / jnp.sum(e, axis=-1, keepdims=True)                # (b, K)
 
     vt = vt_ref[...].astype(jnp.float32)
-    ctx = jnp.dot(alpha, vt, preferred_element_type=jnp.float32)  # (b, d_pad)
+    ctx = jnp.dot(alpha, vt, preferred_element_type=jnp.float32,
+                  precision=_F32)                                 # (b, d_pad)
 
     wo = wo_ref[...].astype(jnp.float32)
-    scores = jnp.dot(ctx, wo, preferred_element_type=jnp.float32)
+    scores = jnp.dot(ctx, wo, preferred_element_type=jnp.float32,
+                     precision=_F32)
     out_ref[...] = (scores + bo_ref[0, :][None, :]).astype(out_ref.dtype)
 
 

@@ -14,12 +14,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 v5e pod mesh (data, model); 2 pods adds a leading "pod" axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1):
     """Tiny mesh for CPU integration tests (requires that many devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=_auto(2))
+
+
+def _auto(n: int):
+    """Auto axes: the model code constrains activations with
+    ``with_sharding_constraint``, which only accepts Auto mesh axes (jax
+    0.9's ``make_mesh`` defaults to Explicit)."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 # --- TPU v5e per-chip constants (assignment-specified) ----------------------

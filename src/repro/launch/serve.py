@@ -22,8 +22,9 @@ answer's risk-adjusted quality beats paying for generation. ``--save-router`` / 
 persist the trained router (params + version + cost-scaler meta); restored
 routers score bitwise-identically.
 
-Builds reduced pool members on CPU (full configs require the production
-mesh), trains the attention router on synthetic RouterBench traffic mapped
+Builds pool members at the reduced smoke widths by default (what the CPU
+tests run) or, with ``--full-width``, at their published widths (what one
+chip runs: ``chip_smoke.py``), trains the attention router on synthetic RouterBench traffic mapped
 onto the pool, then replays a simulated traffic scenario (poisson / bursty /
 drift) through the admission queue + continuous micro-batching scheduler,
 reporting per-member counts, spend vs. budget, and latency percentiles.
@@ -64,7 +65,9 @@ import types
 import jax
 import numpy as np
 
-from repro.configs import get_smoke_config
+from repro.common import tree_count
+from repro.common.compile_cache import enable_compile_cache
+from repro.configs import get_config, get_smoke_config
 from repro.core import build_model_embeddings
 from repro.core.router import PredictiveRouter
 from repro.data import generate
@@ -86,15 +89,14 @@ from repro.serving import (
 from repro.training import train_dual_predictors
 
 
-def build_pool(names, seed: int = 0):
-    """Reduced configs execute on CPU; cost rates come from the FULL
-    configs (the economics the router must learn are those of the real
-    architectures, not of the smoke-test stand-ins)."""
-    from repro.configs import get_config
-
+def build_pool(names, seed: int = 0, full_width: bool = False):
+    """Pool members at their published widths (``full_width``) or at the
+    reduced smoke widths. Cost rates always come from the published
+    configs: the economics the router must learn are those of the real
+    architectures, not of the smoke-test stand-ins."""
     members = []
     for i, name in enumerate(names):
-        cfg = get_smoke_config(name)
+        cfg = get_config(name) if full_width else get_smoke_config(name)
         params = lm_mod.init_lm(jax.random.key(seed + i), cfg)
         members.append(PoolMember(
             name=name, cfg=cfg, params=params,
@@ -127,16 +129,17 @@ def synthetic_pool_traffic(pool, n: int = 1200, seed: int = 0):
 def build_routed_engine(names, *, seed: int = 0, epochs: int = 120,
                         lam: float = 1.0, n_traffic: int = 1200,
                         use_pallas: bool = False, quality_kind: str = "attn",
-                        restore_router: str = None):
+                        restore_router: str = None, full_width: bool = False):
     """Pool + trained router + engine, all seeded. Returns (engine, data, te).
 
     ``quality_kind="attn-ens"`` trains the deep-ensemble quality head (the
     cascade path's uncertainty source). ``restore_router`` skips offline
     predictor training entirely and loads a checkpoint saved by
     ``--save-router`` instead (the pool and traffic corpus are still built
-    — they are the serving substrate, not router state).
+    — they are the serving substrate, not router state). ``full_width``
+    builds the members at their published widths (see :func:`build_pool`).
     """
-    pool = build_pool(names, seed=seed)
+    pool = build_pool(names, seed=seed, full_width=full_width)
     data, quality, cost = synthetic_pool_traffic(pool, n=n_traffic, seed=seed)
     tr, va, te = data.split(seed=seed)
     if restore_router is not None:
@@ -179,7 +182,7 @@ def build_context(args):
         names, seed=args.seed, epochs=args.epochs, lam=args.lam,
         use_pallas=args.pallas,
         quality_kind="attn-ens" if args.cascade else "attn",
-        restore_router=args.restore_router)
+        restore_router=args.restore_router, full_width=args.full_width)
 
     # Quality truth lookup (--online feedback and --cascade per-leg
     # observed quality), built once and shared by every consumer.
@@ -470,8 +473,13 @@ def make_parser() -> argparse.ArgumentParser:
     ap.add_argument("--queue-capacity", type=int, default=512)
     ap.add_argument("--deadline", type=float, default=None,
                     help="per-request deadline, virtual seconds after arrival")
+    ap.add_argument("--full-width", action="store_true",
+                    help="build pool members at their published widths "
+                         "instead of the reduced smoke configs")
     ap.add_argument("--pallas", action="store_true",
-                    help="score through the fused Pallas router_xattn path")
+                    help="on the CPU, score through the fused Pallas "
+                         "router_xattn kernel in interpret mode (on TPU "
+                         "the kernel always scores)")
     ap.add_argument("--wall-time", action="store_true",
                     help="advance the virtual clock by measured wall time "
                          "instead of the deterministic service model")
@@ -606,7 +614,12 @@ def make_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main(argv=None):
+def main(argv=None, *, devices=None):
+    """Run one serve invocation; returns the run's summary dict.
+
+    ``devices`` (programmatic callers only) are the devices an in-process
+    plane spreads its pool members over; default: every local device.
+    """
     ap = make_parser()
     args = ap.parse_args(argv)
     # Socket mode forwards the raw argv to follower processes, which
@@ -622,8 +635,16 @@ def main(argv=None):
         if args.crash_at is not None and args.crash_worker == 0:
             ap.error("--transport socket pins the controller (and leader) "
                      "to worker 0; crash a follower instead")
+        if jax.default_backend() == "tpu":
+            ap.error("--transport socket starts follower processes that "
+                     "each need the chip, but this controller process "
+                     "already holds it (a chip belongs to one process); "
+                     "use --transport local")
+    enable_compile_cache()
 
     ctx = build_context(args)
+    pool_desc = [_describe_member(m) for m in ctx.engine.pool]
+    print("pool: " + "  ".join(pool_desc))
     if args.save_router:
         from repro.checkpoint import save_router
 
@@ -663,11 +684,42 @@ def main(argv=None):
             if args.transport == "socket":
                 return _run_plane_socket(args, ctx, trace, obs, raw_argv,
                                          mserver=mserver)
-            return _run_plane(args, ctx, trace, obs)
-        return _run_solo(args, ctx, trace, obs)
+            summary = _run_plane(args, ctx, trace, obs, devices)
+        else:
+            summary = _run_solo(args, ctx, trace, obs)
+        # In-process runs hold every request: its generated tokens (or
+        # None if it never completed), in trace order.
+        summary["pool"] = pool_desc
+        summary["outputs"] = [None if r.output is None
+                              else np.asarray(r.output).tolist()
+                              for r in trace]
+        summary["peak_bytes_in_use"] = _peak_memory()
+        return summary
     finally:
         if mserver is not None:
             mserver.stop()
+
+
+def _describe_member(m) -> str:
+    """One member's widths as built, with its parameter count and dtype."""
+    c = m.cfg
+    moe = f" {c.n_experts} experts top-{c.top_k}" if c.n_experts else ""
+    dtype = jax.tree.leaves(m.params)[0].dtype
+    return (f"{m.name} d_model {c.d_model} x {c.n_layers} layers vocab "
+            f"{c.vocab_size}{moe} ({tree_count(m.params)} {dtype} params)")
+
+
+def _peak_memory() -> dict:
+    """Peak bytes in use per local device, where the backend reports it
+    (printed; the CPU backend reports nothing)."""
+    peaks = {}
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        if "peak_bytes_in_use" in stats:
+            peaks[d.id] = int(stats["peak_bytes_in_use"])
+            print(f"peak device memory {d.platform}:{d.id} "
+                  f"{peaks[d.id]} bytes")
+    return peaks
 
 
 def _run_solo(args, ctx, trace, obs):
@@ -769,12 +821,17 @@ def _run_solo(args, ctx, trace, obs):
     return summary
 
 
-def _run_plane(args, ctx, trace, obs):
-    """Multi-worker path over LocalTransport: N in-process workers."""
+def _run_plane(args, ctx, trace, obs, devices=None):
+    """Multi-worker path over LocalTransport: N in-process workers.
+
+    Each pool member's parameters sit on its owning worker's device
+    (:func:`repro.distributed.shard.place_pool` over ``devices``).
+    """
     from repro.distributed import (
         Coordinator, PlaneEvent, ServingPlane, SharedBudgetLedger,
         SyncConfig,
     )
+    from repro.distributed.shard import place_pool
 
     recorder, registry, profiler, flusher = obs
     # One fleet-level SLO tracker: every worker's finalized requests feed
@@ -785,6 +842,10 @@ def _run_plane(args, ctx, trace, obs):
         governor = SharedBudgetLedger(args.budget, args.budget_window,
                                       lam0=args.lam)
 
+    placed = place_pool(ctx.engine.pool, args.workers, devices)
+    print("pool placement: " + "  ".join(
+        f"{m.name}->{d.platform}:{d.id}"
+        for m, d in zip(ctx.engine.pool, placed)))
     drift_proto = build_drift_proto(args, ctx)
     workers = [
         build_plane_worker(args, ctx, wid, governor, drift_proto,
@@ -818,6 +879,8 @@ def _run_plane(args, ctx, trace, obs):
         if flusher is not None:
             register_stream_metrics(registry, flusher)
     summary = plane.run_trace(trace)
+    summary["pool_device"] = {m.name: d.id
+                              for m, d in zip(ctx.engine.pool, placed)}
 
     print(f"trace={args.trace} requests={args.requests} seed={args.seed} "
           f"workers={args.workers}")

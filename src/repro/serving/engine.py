@@ -4,8 +4,8 @@ Flow per score batch:
     text -> featurizer -> dual predictors (quality, cost) -> reward argmax
          -> dispatch to the chosen pool member's generate loop.
 
-The pool members are the assigned architectures (reduced configs on CPU,
-full configs under the production mesh). Each member's $ cost rate derives
+The pool members are the assigned architectures (reduced configs for the
+CPU tests, published widths on the chip). Each member's $ cost rate derives
 from its *active* parameter count — 2*N_active FLOPs/token at a fixed
 $/FLOP — so the router's cost axis is grounded in real model economics
 rather than API price tables.
@@ -14,10 +14,11 @@ rather than API price tables.
 queue, no clock, and no budget — the streaming scheduler
 (:mod:`repro.serving.scheduler`) drives it. The router's scoring hot path
 runs through the fused Pallas kernel (``repro.kernels.ops.router_xattn_pool``)
-when the quality predictor is the attention variant, with the pool-side
-K~/V~ projections computed once per pool and reused across every score
-batch; elsewhere it falls back to the jnp reference path (identical math,
-see kernels/ref.py).
+on TPU when the quality predictor is the attention variant, with the
+pool-side K~/V~ projections computed once per pool and reused across every
+score batch; on the CPU it runs the jnp reference path (identical math,
+see kernels/ref.py) unless ``use_pallas`` asks for the kernel in
+interpret mode.
 """
 from __future__ import annotations
 
@@ -110,6 +111,8 @@ class RoutedEngine:
     router: PredictiveRouter
     pool: List[PoolMember]
     lam: float = 1.0
+    # Score through the fused Pallas kernel. Always on where the kernel
+    # compiles (TPU); on the CPU the jnp reference scores unless asked.
     use_pallas: bool = False
     # Observability hook: called with the new router version after every
     # successful swap (the scheduler wires this to the trace recorder).
@@ -117,6 +120,10 @@ class RoutedEngine:
         default=None, repr=False)
     _pool_proj: Optional[Tuple[jax.Array, jax.Array]] = dataclasses.field(
         default=None, repr=False)
+
+    def __post_init__(self):
+        self.use_pallas = (bool(self.use_pallas)
+                           or jax.default_backend() == "tpu")
 
     # -- scoring ------------------------------------------------------------
 

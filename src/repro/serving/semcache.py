@@ -199,14 +199,22 @@ class SemanticCache:
             q[b:] = 0.0
             d2 = np.asarray(kops.pairwise_l2(q, self._emb_buf))[:b, :n]
         nn = np.argmin(d2, axis=1)
-        r2 = self.radius * self.radius
-        out: List[Optional[Tuple[int, float]]] = []
-        for i in range(b):
-            j = int(nn[i])
-            v = float(d2[i, j])
-            out.append((j, math.sqrt(v) if v > 0.0 else 0.0)
-                       if v <= r2 else None)
-        return out
+        return [self._within(q_emb[i], int(nn[i])) for i in range(b)]
+
+    def _within(self, emb: np.ndarray,
+                j: int) -> Optional[Tuple[int, float]]:
+        """``(j, distance)`` when entry ``j`` lies within the radius.
+
+        The float32 expansion above only picks the nearest entry; the
+        radius test recomputes that one distance in float64, against the
+        radius held at the embeddings' float32 precision, so a query at
+        exactly the radius is a hit on every path.
+        """
+        diff = emb.astype(np.float64) - self._emb_buf[j]
+        v = float(diff @ diff)
+        if v > float(np.float32(self.radius)) ** 2:
+            return None
+        return (j, math.sqrt(v))
 
     def decide(self, hit: Optional[Tuple[int, float]], lam: float, *,
                headroom: float = 1.0) -> CacheVerdict:
@@ -263,11 +271,7 @@ class SemanticCache:
         # (n, d) difference materialization per admission.
         d2 = (self._norm_buf[:n] - 2.0 * (self._emb_buf[:n] @ emb)
               + float(emb @ emb))
-        j = int(np.argmin(d2))
-        v = float(d2[j])
-        if v > self.radius * self.radius:
-            return None
-        return (j, math.sqrt(v) if v > 0.0 else 0.0)
+        return self._within(emb, int(np.argmin(d2)))
 
     # -- admission / eviction -------------------------------------------------
 

@@ -152,7 +152,9 @@ class Histogram:
                     lo, hi = max(self.edges[-1], self.min), self.max
                 else:
                     lo, hi = self.edges[i - 1], self.edges[i]
-                if lo > 0 and hi > 0:
+                if frac >= 1.0:
+                    est = hi        # exact: the power below can miss by an ulp
+                elif lo > 0 and hi > 0:
                     est = lo * (hi / lo) ** frac
                 else:
                     est = lo + (hi - lo) * frac

@@ -302,3 +302,49 @@ class TestSharedBudgetLedger:
         assert ts == sorted(ts)
         # both events are inside the [hwm - window, hwm] window
         assert ledger.window_spend(5.0) == pytest.approx(0.6)
+
+
+class TestPlacePool:
+    """Each member's parameters go to its owning worker's device."""
+
+    @staticmethod
+    def _pool(n):
+        import types
+
+        return [types.SimpleNamespace(params={"w": np.ones(3, np.float32)})
+                for _ in range(n)]
+
+    def test_one_device_holds_everything(self):
+        import jax
+
+        from repro.distributed.shard import place_pool
+
+        pool = self._pool(3)
+        placed = place_pool(pool, 4, devices=jax.devices()[:1])
+        assert [d.id for d in placed] == [jax.devices()[0].id] * 3
+        assert all(m.params["w"].devices() == {placed[0]} for m in pool)
+
+    def test_owners_devices_on_four(self):
+        import os
+        import subprocess
+        import sys
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        script = (
+            "import types, numpy as np\n"
+            "from repro.distributed.shard import place_pool\n"
+            "for n_workers in (4, 2):\n"
+            "    pool = [types.SimpleNamespace(params={'w': np.ones(3)})"
+            " for _ in range(3)]\n"
+            "    placed = place_pool(pool, n_workers)\n"
+            "    print([d.id for d in placed],"
+            " [next(iter(m.params['w'].devices())).id for m in pool])\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                   PYTHONPATH=os.pathsep.join(
+                       [os.path.join(root, "src")]
+                       + [p for p in [os.environ.get("PYTHONPATH")] if p]))
+        out = subprocess.run([sys.executable, "-c", script], env=env,
+                             check=True, capture_output=True, text=True,
+                             timeout=120).stdout.splitlines()
+        assert out == ["[0, 1, 2] [0, 1, 2]", "[0, 1, 0] [0, 1, 0]"]

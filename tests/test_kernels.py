@@ -106,3 +106,17 @@ def test_pairwise_l2_matches_clustering_module():
         np.asarray(pairwise_sq_dists(x, c)),
         rtol=1e-4, atol=1e-4,
     )
+
+
+@pytest.mark.parametrize("platform,interpret", [("cpu", True),
+                                                ("tpu", False)])
+def test_interpret_mode_follows_the_platform(monkeypatch, platform,
+                                             interpret):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert ops._interpret() is interpret
+
+
+def test_no_interpret_fallback_on_other_platforms(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="target TPU"):
+        ops._interpret()

@@ -2,8 +2,11 @@
 
 These tests run on 1 CPU device: sharding *rules* are exercised against an
 AbstractMesh with the production 16x16 shape (no real devices needed), and a
-real (1,1) mesh covers the end-to-end jit path.
+real (1,1) mesh covers the end-to-end jit path. The persistent compilation
+cache helper's placement is checked last.
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,18 +25,10 @@ from repro.launch.sharding import (
 from repro.models import lm as lm_mod
 
 
-def _make_abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        # jax<=0.4.x: AbstractMesh(shape_tuple) of (name, size) pairs.
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 def abstract_mesh(multi_pod=False):
     if multi_pod:
-        return _make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return _make_abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 class TestParamSpecs:
@@ -213,3 +208,68 @@ class TestSmallMeshEndToEnd:
         with mesh:
             loss, params, opt = step(params, opt, batch)
         assert np.isfinite(float(loss))
+
+
+class TestCompileCache:
+    ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def test_env_var_is_left_to_jax(self, monkeypatch):
+        from repro.common.compile_cache import ENV_VAR, enable_compile_cache
+
+        monkeypatch.setenv(ENV_VAR, "/elsewhere/cache")
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_dir_in_checkout(self, monkeypatch):
+        from repro.common.compile_cache import ENV_VAR, enable_compile_cache
+
+        monkeypatch.delenv(ENV_VAR, raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            path = enable_compile_cache()
+            assert path == os.path.join(self.ROOT, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
+    @pytest.mark.parametrize("env_set", [True, False],
+                             ids=["env-dir", "checkout-dir"])
+    def test_compiles_land_in_one_place(self, tmp_path, env_set):
+        """A process writes its compiles to the one cache directory, and a
+        second process of the same checkout reads them back. The checkout
+        is a copy whose ``src`` links to this one, so the default
+        ``.jax_cache/`` lands in ``tmp_path``."""
+        import subprocess
+        import sys
+
+        checkout = tmp_path / "checkout"
+        checkout.mkdir()
+        os.symlink(os.path.join(self.ROOT, "src"), checkout / "src")
+        cache = (tmp_path / "env-cache" if env_set
+                 else checkout / ".jax_cache")
+        script = (
+            "import jax, jax.numpy as jnp\n"
+            "from jax import monitoring\n"
+            "from repro.common.compile_cache import enable_compile_cache\n"
+            "hits = []\n"
+            "monitoring.register_event_listener(lambda e, **_: hits.append(e)"
+            " if e == '/jax/compilation_cache/cache_hits' else None)\n"
+            "enable_compile_cache()\n"
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs',"
+            " 0)\n"
+            "jax.jit(lambda x: x @ x + 1)(jnp.ones((64, 64)))"
+            ".block_until_ready()\n"
+            "print(len(hits))\n")
+        env = {k: v for k, v in os.environ.items()
+               if k not in ("JAX_COMPILATION_CACHE_DIR", "PYTHONPATH")}
+        env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(checkout / "src"))
+        if env_set:
+            env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+        hits = [subprocess.run([sys.executable, "-c", script], env=env,
+                               check=True, capture_output=True, text=True,
+                               timeout=120, cwd=tmp_path).stdout.split()[-1]
+                for _ in range(2)]
+        assert any(n.startswith("jit__lambda") for n in os.listdir(cache))
+        assert os.path.isdir(checkout / ".jax_cache") is not env_set
+        assert hits[0] == "0" and int(hits[1]) >= 1

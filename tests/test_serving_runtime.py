@@ -15,6 +15,7 @@ from repro.serving import (
     Histogram,
     MicroBatchScheduler,
     Request,
+    RoutedEngine,
     SchedulerConfig,
     TraceConfig,
     make_trace,
@@ -426,3 +427,45 @@ class TestEndToEndSimulatedTraffic:
         assert len(res["outputs"]) == 5
         assert all(o is not None and o.shape == (2,) for o in res["outputs"])
         assert res["per_member_counts"].sum() == 5
+
+
+class TestPlatformDefaults:
+    def _router(self):
+        import jax
+
+        from repro.core.predictors import PREDICTORS
+        from repro.core.router import PredictiveRouter
+
+        key = jax.random.key(0)
+        qp = PREDICTORS["attn"].init(key, 8, 2, 4)
+        cp = PREDICTORS["attn"].init(key, 8, 2, 4)
+        return PredictiveRouter("attn", "attn", qp, cp,
+                                np.ones((2, 4), np.float32))
+
+    @pytest.mark.parametrize("platform,asked,used", [
+        ("cpu", False, False), ("cpu", True, True),
+        ("tpu", False, True), ("tpu", True, True)])
+    def test_kernel_scoring_follows_the_platform(self, monkeypatch,
+                                                 platform, asked, used):
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        eng = RoutedEngine(router=self._router(), pool=[],
+                           use_pallas=asked)
+        assert eng.use_pallas is used
+
+    @pytest.mark.parametrize("full_width", [False, True])
+    def test_build_pool_width(self, monkeypatch, full_width):
+        """``full_width`` takes the published config; a stand-in keeps the
+        test off a full-size model on the CPU."""
+        import dataclasses
+
+        from repro.configs import get_smoke_config
+        from repro.launch import serve
+
+        stand_in = dataclasses.replace(get_smoke_config("qwen3-0.6b"),
+                                       name="published-stand-in")
+        monkeypatch.setattr(serve, "get_config", lambda name: stand_in)
+        (member,) = serve.build_pool(["qwen3-0.6b"], full_width=full_width)
+        assert (member.cfg is stand_in) is full_width
+        assert member.cost_rate == serve.arch_cost_rate(stand_in)

@@ -725,3 +725,23 @@ class TestPoolDispatch:
         disp = PoolDispatcher(0, 2, workers[0].engine, lt)
         with pytest.raises(TransportError):
             disp.generate_member(1, [np.arange(3, dtype=np.int32)])
+
+
+class TestSocketRefusedOnTpu:
+    def test_refuses_before_spawning_a_follower(self, monkeypatch, capsys):
+        """A chip belongs to one process: on TPU the controller would hold
+        it while its followers wait on its lock, so socket mode exits up
+        front instead of starting them."""
+        import subprocess
+
+        from repro.launch import serve
+
+        def no_spawn(*a, **kw):
+            raise AssertionError("a follower process was started")
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(subprocess, "Popen", no_spawn)
+        with pytest.raises(SystemExit) as exc:
+            serve.main(["--workers", "2", "--transport", "socket"])
+        assert exc.value.code == 2
+        assert "already holds it" in capsys.readouterr().err
