@@ -26,7 +26,6 @@ of a passing run is one JSON object naming the device.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import os
 import sys
@@ -38,8 +37,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from jax import monitoring  # noqa: E402
 
+from repro.common import profile_slot  # noqa: E402
 from repro.common.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_config, get_smoke_config  # noqa: E402
 from repro.core.model_repr import N_CLUSTERS  # noqa: E402
@@ -49,7 +48,7 @@ from repro.kernels import ops as kops  # noqa: E402
 from repro.kernels import ref as kref  # noqa: E402
 from repro.launch import serve  # noqa: E402
 from repro.models import lm as lm_mod  # noqa: E402
-from repro.obs.profiling import KernelProfiler  # noqa: E402
+from repro.obs.profiling import LayerProfiler  # noqa: E402
 
 POOL = ("qwen3-0.6b", "granite-moe-1b-a400m")
 REQUESTS = 16
@@ -88,39 +87,22 @@ def check(cond, msg: str) -> None:
         fail(msg)
 
 
-class CompileCounter:
-    """XLA compile requests and persistent-cache hits, from JAX's own
-    monitoring events (a hit is a request that skipped compilation)."""
+def profiled(call):
+    """Run ``call()`` with a fresh layer profiler installed: (its result,
+    the profiler, which holds the XLA compiles and persistent-cache hits
+    of the call, charged to the program's spans)."""
+    prof = LayerProfiler()
+    profile_slot.install(prof)
+    try:
+        return call(), prof
+    finally:
+        profile_slot.install(None)
 
-    def __init__(self):
-        self.requests = 0
-        self.hits = 0
 
-    def __enter__(self):
-        monitoring.register_event_duration_secs_listener(self._duration)
-        monitoring.register_event_listener(self._event)
-        return self
-
-    def __exit__(self, *exc):
-        monitoring.unregister_event_duration_listener(self._duration)
-        monitoring.unregister_event_listener(self._event)
-
-    def _duration(self, event, duration_secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.requests += 1
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    @contextlib.contextmanager
-    def phase(self, out: dict):
-        r0, h0 = self.requests, self.hits
-        try:
-            yield
-        finally:
-            out["compiles"] = self.requests - r0
-            out["cache_hits"] = self.hits - h0
+def compile_split(prof) -> dict:
+    """Compiles charged to each span, most first."""
+    return dict(sorted(((k, v) for k, v in prof.compiles.items() if v),
+                       key=lambda kv: -kv[1]))
 
 
 def unit_rows(key, n: int, d: int):
@@ -211,34 +193,29 @@ def check_served(summary: dict, label: str) -> None:
           f"{label}: not every member served: {counts}")
 
 
-def phase_serve(platform: str, seed: int, full_width: bool,
-                compiles: CompileCounter) -> None:
-    kernels = KernelProfiler()
-    stats = {}
-    kops.set_kernel_profiler(kernels)
-    try:
-        with compiles.phase(stats):
-            summary = serve.main(serve_argv(seed, full_width))
-    finally:
-        kops.set_kernel_profiler(None)
+def phase_serve(platform: str, seed: int, full_width: bool) -> None:
+    summary, prof = profiled(lambda: serve.main(serve_argv(seed,
+                                                           full_width)))
     check_served(summary, "serve")
     # Radius calibration runs pairwise_l2; on TPU the engine scores
     # through the kernel with no flag asking for it.
-    expect = ["pairwise_l2"] + (["router_xattn_pool"]
-                                if platform == "tpu" else [])
-    check(all(kernels.calls.get(name, 0) >= 1 for name in expect),
-          f"serve path dispatched kernels {dict(kernels.calls)}, "
-          f"expected {expect}")
+    expect = ["repro.kernels.pairwise_l2"] + (
+        ["repro.kernels.router_xattn_pool"] if platform == "tpu" else [])
+    kernels = {k: v for k, v in sorted(prof.calls.items())
+               if k.startswith("repro.kernels.")}
+    check(all(kernels.get(name, 0) >= 1 for name in expect),
+          f"serve path dispatched kernels {kernels}, expected {expect}")
+    stats = prof.totals()
     peak = summary["peak_bytes_in_use"]
     check(platform != "tpu" or peak, "no peak device memory reported")
     print(f"(c) serve: {'  '.join(summary['pool'])}; completed "
           f"{summary['completed']}/"
           f"{REQUESTS} with {MAX_NEW} tokens each; per-member "
           f"{summary['per_member_counts']}; cache hits "
-          f"{summary.get('cache_hits', 0)}; kernel calls "
-          f"{dict(sorted(kernels.calls.items()))}; {stats['compiles']} XLA "
-          f"compile requests ({stats['cache_hits']} served from the "
-          f"persistent cache); "
+          f"{summary.get('cache_hits', 0)}; kernel calls {kernels}; "
+          f"{stats['compiles']} XLA compile requests ({stats['cache_hits']} "
+          f"served from the persistent cache; by span "
+          f"{compile_split(prof)}); "
           f"peak device memory {peak} bytes", flush=True)
 
 
@@ -272,18 +249,16 @@ def phase_cache(seed: int, full_width: bool) -> None:
     check(err <= CACHE_TOL, f"cached logits off the full forward by {err:.3e}")
 
 
-def phase_fleet(seed: int, full_width: bool, n_workers: int,
-                compiles: CompileCounter) -> None:
+def phase_fleet(seed: int, full_width: bool, n_workers: int) -> None:
     argv = serve_argv(seed, full_width) + [
         "--workers", str(n_workers), "--transport", "local", "--online",
         "--online-update-every", "4"]
     runs = {}
     for label, devices in (("spread", None), ("chip0", jax.devices()[:1])):
-        stats = {}
-        with compiles.phase(stats):
-            runs[label] = serve.main(argv, devices=devices)
+        runs[label], prof = profiled(
+            lambda: serve.main(argv, devices=devices))
         check_served(runs[label], label)
-        runs[label]["stats"] = stats
+        runs[label]["stats"] = prof.totals()
     spread, one = runs["spread"], runs["chip0"]
     check(len(set(spread["pool_device"].values())) == len(POOL),
           f"members share devices: {spread['pool_device']}")
@@ -307,13 +282,12 @@ def run(chips: int = 1, seed: int = 0, *, platform: str = "tpu",
     """All phases for ``chips``; returns the device record. The CPU tests
     call this with ``platform="cpu"`` and ``full_width=False``."""
     device = phase_device(platform, chips)
-    with CompileCounter() as compiles:
-        if chips > 1:
-            phase_fleet(seed, full_width, chips, compiles)
-        else:
-            phase_kernels(platform, seed)
-            phase_serve(platform, seed, full_width, compiles)
-            phase_cache(seed, full_width)
+    if chips > 1:
+        phase_fleet(seed, full_width, chips)
+    else:
+        phase_kernels(platform, seed)
+        phase_serve(platform, seed, full_width)
+        phase_cache(seed, full_width)
     return device
 
 

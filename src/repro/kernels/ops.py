@@ -4,14 +4,18 @@ Handle padding to TPU tile granularity (128 lanes), interpret mode on the
 CPU, and un-padding of results. The rest of the codebase calls only these
 entry points.
 
-Profiling: :func:`set_kernel_profiler` installs a
-:class:`repro.obs.profiling.KernelProfiler` (or anything with a compatible
-``annotate(name, batch=...)`` context manager) around the serving-hot
-entry points — ``router_xattn_pool`` and ``pairwise_l2``. With a profiler
-installed each dispatch blocks until the result is ready (so the timing
-covers device work, not just dispatch) and lands in per-kernel latency
-histograms / per-batch trace spans; with none installed (the default) the
-call goes straight to the jit'd function.
+Profiling: the serving-hot entry points, ``router_xattn_pool`` and
+``pairwise_l2``, read the program's one profiler slot
+(:func:`repro.common.profile_slot.active`). With nothing installed (the
+default) each call goes straight to the jit'd function: one ``None``
+test, no annotation, clock read or sync. With a
+:class:`repro.obs.profiling.LayerProfiler` installed
+(:func:`repro.common.profile_slot.install`) each dispatch runs as the
+span ``repro.kernels.router_xattn_pool`` or ``repro.kernels.pairwise_l2``
+(arg ``n``, the batch rows) and blocks until the result is ready, so the
+span covers the device work and not just the dispatch. The other
+layers' spans, and the compiles the profiler charges to each, are listed
+in :mod:`repro.obs.profiling`.
 """
 from __future__ import annotations
 
@@ -20,23 +24,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.common.profile_slot import active
 from repro.kernels.pairwise_l2 import pairwise_l2_pallas
 from repro.kernels.router_xattn import router_xattn_pallas
 
 LANE = 128
-
-# Installed profiler (None = zero-overhead pass-through).
-_PROFILER = None
-
-
-def set_kernel_profiler(profiler) -> None:
-    """Install (or with ``None`` remove) the kernel dispatch profiler."""
-    global _PROFILER
-    _PROFILER = profiler
-
-
-def get_kernel_profiler():
-    return _PROFILER
 
 
 def _interpret() -> bool:
@@ -132,10 +124,11 @@ def router_xattn_pool(
     are computed once per pool and reused across every score micro-batch,
     so the per-batch work is only the query-side projection + attention.
     """
-    if _PROFILER is None:
+    prof = active()
+    if prof is None:
         return _router_xattn_pool_jit(q, wq, kt, vt, wo, bo,
                                       block_b=block_b, interpret=interpret)
-    with _PROFILER.annotate("router_xattn_pool", batch=int(q.shape[0])):
+    with prof.span("repro.kernels.router_xattn_pool", n=int(q.shape[0])):
         out = _router_xattn_pool_jit(q, wq, kt, vt, wo, bo,
                                      block_b=block_b, interpret=interpret)
         jax.block_until_ready(out)
@@ -166,10 +159,11 @@ def pairwise_l2(
     x, c, *, block_n: int = 256, block_k: int = 256, interpret: bool = None
 ):
     """Squared L2 distances x (N,d) vs c (K,d) -> (N,K) fp32."""
-    if _PROFILER is None:
+    prof = active()
+    if prof is None:
         return _pairwise_l2_jit(x, c, block_n=block_n, block_k=block_k,
                                 interpret=interpret)
-    with _PROFILER.annotate("pairwise_l2", batch=int(x.shape[0])):
+    with prof.span("repro.kernels.pairwise_l2", n=int(x.shape[0])):
         out = _pairwise_l2_jit(x, c, block_n=block_n, block_k=block_k,
                                interpret=interpret)
         jax.block_until_ready(out)

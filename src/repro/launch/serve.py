@@ -334,8 +334,9 @@ def _setup_obs(args):
     --obs-dir``) builds the recorder with the sampler/cap installed and an
     :class:`ObsFlusher` over the segment directory; with no
     ``--scrape-every`` the flusher still applies sampling, in one
-    final-only flush. ``--trace-profile`` additionally installs the
-    kernel-dispatch profiler globally (removed again by :func:`_save_obs`).
+    final-only flush. ``--trace-profile`` additionally installs the layer
+    profiler in the program's one slot (removed again by
+    :func:`_save_obs`).
     ``--metrics-port`` forces the registry on so the HTTP endpoint has
     something to scrape.
     """
@@ -367,11 +368,11 @@ def _setup_obs(args):
             include_wall=args.trace_profile,
             deterministic_metrics=not args.trace_profile)
     if args.trace_profile:
-        from repro.kernels import ops as kops
-        from repro.obs import KernelProfiler
+        from repro.common import profile_slot
+        from repro.obs import LayerProfiler
 
-        profiler = KernelProfiler(tracer=recorder)
-        kops.set_kernel_profiler(profiler)
+        profiler = LayerProfiler(tracer=recorder)
+        profile_slot.install(profiler)
     return recorder, registry, profiler, flusher
 
 
@@ -385,9 +386,9 @@ def _save_obs(args, recorder, registry, profiler, flusher=None,
     replay-stable Chrome trace — minus sampled-out request trees).
     """
     if profiler is not None:
-        from repro.kernels import ops as kops
+        from repro.common import profile_slot
 
-        kops.set_kernel_profiler(None)
+        profile_slot.install(None)
         print(profiler.report())
         if registry is not None:
             profiler.register_metrics(registry)
@@ -567,10 +568,11 @@ def make_parser() -> argparse.ArgumentParser:
                          "text, /metrics.json canonical JSON; 0 picks an "
                          "ephemeral port)")
     ap.add_argument("--trace-profile", action="store_true",
-                    help="profile kernel dispatches (wall clock) and "
-                         "include the wall-clock spans/metrics in the "
-                         "artifacts — the outputs are then NOT "
-                         "replay-stable")
+                    help="profile the scheduler, engine, LM and kernel "
+                         "layers (wall clock, with the XLA compiles each "
+                         "triggers) and include the wall-clock spans/"
+                         "metrics in the artifacts — the outputs are then "
+                         "NOT replay-stable")
     ap.add_argument("--scrape-every", type=float, default=None,
                     metavar="VIRT_S",
                     help="streaming obs: flush completed trace spans and a "

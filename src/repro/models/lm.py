@@ -23,6 +23,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.common.profile_slot import active
 from repro.configs.base import (
     ATTN, MAMBA, MLP, MLSTM, MOE, NONE, SLSTM, XATTN, ArchConfig, LayerSpec,
 )
@@ -492,15 +493,55 @@ def greedy_generate(cfg, params, prompt, max_new: int, media=None,
     batch so every pool member's output — attention, SSM, xLSTM, and MoE
     alike — is invariant to micro-batch composition (see serving engine
     ``pad_prompts`` and tests/test_masked_prefill.py).
+
+    With the layer profiler installed (:mod:`repro.common.profile_slot`)
+    the prefill runs as span ``repro.lm.prefill``, closed once the first
+    token is ready, and the decode steps as ``repro.lm.decode``, closed
+    once the last token is ready, each step inside it a
+    ``repro.lm.decode_step`` (arg ``i``, no sync). The tokens are the
+    same either way.
     """
+    prof = active()
+    if prof is not None:
+        return _greedy_generate_profiled(prof, cfg, params, prompt, max_new,
+                                         media, dtype, attn_mask)
+    s = prompt.shape[1]
+    tok, caches = _first_token(cfg, params, prompt, max_new, media, dtype,
+                               attn_mask)
+    out = [tok]
+    for i in range(max_new - 1):
+        tok, caches = _next_token(cfg, params, tok, caches, s + i)
+        out.append(tok)
+    return jnp.concatenate(out, axis=1)
+
+
+def _first_token(cfg, params, prompt, max_new, media, dtype, attn_mask):
     b, s = prompt.shape
     caches = init_caches(cfg, b, s + max_new, dtype)
     logits, caches = apply_lm_prefill(cfg, params, prompt, caches, media,
                                       attn_mask=attn_mask)
     tok = jnp.argmax(logits[:, -1, : cfg.vocab_size], axis=-1)[:, None]
+    return tok, caches
+
+
+def _next_token(cfg, params, tok, caches, pos: int):
+    logits, caches = apply_lm_decode(cfg, params, tok, caches, jnp.int32(pos))
+    tok = jnp.argmax(logits[:, -1, : cfg.vocab_size], axis=-1)[:, None]
+    return tok, caches
+
+
+def _greedy_generate_profiled(prof, cfg, params, prompt, max_new, media,
+                              dtype, attn_mask):
+    b, s = prompt.shape
+    with prof.span("repro.lm.prefill", n=b, length=s):
+        tok, caches = _first_token(cfg, params, prompt, max_new, media,
+                                   dtype, attn_mask)
+        jax.block_until_ready(tok)
     out = [tok]
-    for i in range(max_new - 1):
-        logits, caches = apply_lm_decode(cfg, params, tok, caches, jnp.int32(s + i))
-        tok = jnp.argmax(logits[:, -1, : cfg.vocab_size], axis=-1)[:, None]
-        out.append(tok)
+    with prof.span("repro.lm.decode", n=b, steps=max_new - 1):
+        for i in range(max_new - 1):
+            with prof.span("repro.lm.decode_step", i=i):
+                tok, caches = _next_token(cfg, params, tok, caches, s + i)
+            out.append(tok)
+        jax.block_until_ready(tok)
     return jnp.concatenate(out, axis=1)

@@ -15,8 +15,10 @@ Pillars over the serving fleet:
     alerting on the virtual clock;
   * :mod:`repro.obs.scrape` — a localhost HTTP endpoint serving the live
     registry (``/metrics`` Prometheus text, ``/metrics.json``);
-  * :mod:`repro.obs.profiling` — wall-clock (+ optional jax profiler)
-    timing hooks around the Pallas kernel entry points.
+  * :mod:`repro.obs.profiling` — the wall-clock layer profiler: spans
+    of the scheduler, engine, LM and kernels (each also a
+    ``jax.profiler`` annotation) and the XLA compiles charged to them,
+    installed through :mod:`repro.common.profile_slot`.
 
 ``repro.obs.wiring`` registers the standard serving metric series;
 ``launch/serve.py`` wires everything into the serving driver
@@ -31,7 +33,7 @@ from repro.obs.metrics import (
     MetricsRegistry,
     MultiGauge,
 )
-from repro.obs.profiling import KernelProfiler
+from repro.obs.profiling import LayerProfiler
 from repro.obs.sampling import TraceSampler, is_anomaly_event
 from repro.obs.scrape import MetricsServer, merge_prom_texts
 from repro.obs.slo import (
@@ -66,7 +68,7 @@ __all__ = [
     "Counter",
     "Gauge",
     "HistogramMetric",
-    "KernelProfiler",
+    "LayerProfiler",
     "MetricsRegistry",
     "MetricsServer",
     "MultiGauge",

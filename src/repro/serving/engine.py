@@ -30,7 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.predictors import PREDICTORS
+from repro.common.profile_slot import active
+from repro.core.predictors import ENSEMBLE_KINDS, PREDICTORS
 from repro.core.rewards import REWARDS
 from repro.core.router import PredictiveRouter
 from repro.data.featurizer import embed_texts
@@ -140,31 +141,43 @@ class RoutedEngine:
         self._pool_proj = None
 
     def _scores(self, q_emb: np.ndarray):
-        if self.use_pallas and self.router.quality_kind == "attn":
-            qp = self.router.quality_params
-            kt, vt = self.pool_projections()
-            # Bucket the batch dim to multiples of 64 *outside* the jit
-            # boundary: scheduler batches vary per round, and jit keys on
-            # the raw shape — without bucketing every distinct batch size
-            # would retrace and recompile the kernel.
-            b = q_emb.shape[0]
-            b_pad = -(-b // 64) * 64
-            q = jnp.asarray(np.pad(np.asarray(q_emb, np.float32),
-                                   ((0, b_pad - b), (0, 0))))
-            s_hat = np.asarray(kops.router_xattn_pool(
-                q, qp["wq"], kt, vt, qp["wo"], qp["bo"]))[:b]
-            cp = self.router.cost_params
-            c_hat = self.router.denormalize_cost(
-                PREDICTORS[self.router.cost_kind].apply(
-                    cp, jnp.asarray(q_emb), jnp.asarray(self.router.model_emb)))
-            return s_hat, c_hat
-        return self.router.predict(q_emb)
+        kernel = self.use_pallas and self.router.quality_kind == "attn"
+        score = self._kernel_scores if kernel else self.router.predict
+        prof = active()
+        if prof is None:
+            return score(q_emb)
+        with prof.span("repro.engine.score", n=len(q_emb),
+                       path="kernel" if kernel else "jnp"):
+            return score(q_emb)
+
+    def _kernel_scores(self, q_emb: np.ndarray):
+        qp = self.router.quality_params
+        kt, vt = self.pool_projections()
+        # Bucket the batch dim to multiples of 64 *outside* the jit
+        # boundary: scheduler batches vary per round, and jit keys on
+        # the raw shape — without bucketing every distinct batch size
+        # would retrace and recompile the kernel.
+        b = q_emb.shape[0]
+        b_pad = -(-b // 64) * 64
+        q = jnp.asarray(np.pad(np.asarray(q_emb, np.float32),
+                               ((0, b_pad - b), (0, 0))))
+        s_hat = np.asarray(kops.router_xattn_pool(
+            q, qp["wq"], kt, vt, qp["wo"], qp["bo"]))[:b]
+        cp = self.router.cost_params
+        c_hat = self.router.denormalize_cost(
+            PREDICTORS[self.router.cost_kind].apply(
+                cp, jnp.asarray(q_emb), jnp.asarray(self.router.model_emb)))
+        return s_hat, c_hat
 
     def embed(self, texts: Sequence[str]) -> np.ndarray:
         """Query embeddings (B, dq) — exposed so the online adapter can
         reuse the scoring pass's embeddings for replay/drift without a
         second featurizer pass."""
-        return embed_texts(texts)
+        prof = active()
+        if prof is None:
+            return embed_texts(texts)
+        with prof.span("repro.engine.embed", n=len(texts)):
+            return embed_texts(texts)
 
     def score_emb(self, q_emb: np.ndarray):
         """(s_hat, c_hat), both (B, K), from precomputed embeddings."""
@@ -179,7 +192,13 @@ class RoutedEngine:
         the fused Pallas kernel computes a single output head, and the
         per-head spread is exactly what it would fuse away.
         """
-        return self.router.predict_with_uncertainty(q_emb)
+        prof = active()
+        if prof is None:
+            return self.router.predict_with_uncertainty(q_emb)
+        path = ("ensemble" if self.router.quality_kind in ENSEMBLE_KINDS
+                else "jnp")
+        with prof.span("repro.engine.score", n=len(q_emb), path=path):
+            return self.router.predict_with_uncertainty(q_emb)
 
     def score_texts(self, texts: Sequence[str]):
         """(s_hat, c_hat), both (B, K) — one fused pass over the batch."""
@@ -238,6 +257,17 @@ class RoutedEngine:
         generates to the chunk max) — at the member's per-token rate,
         never a flat per-request price.
         """
+        prof = active()
+        if prof is None:
+            return self._generate_member(member_idx, prompts, max_new,
+                                         max_new_per_req)
+        with prof.span("repro.engine.generate",
+                       member=self.pool[member_idx].name, n=len(prompts),
+                       length=max(len(p) for p in prompts), max_new=max_new):
+            return self._generate_member(member_idx, prompts, max_new,
+                                         max_new_per_req)
+
+    def _generate_member(self, member_idx, prompts, max_new, max_new_per_req):
         member = self.pool[member_idx]
         toks = member.generate(pad_prompts(prompts), max_new=max_new,
                                attn_mask=prompt_pad_mask(prompts))

@@ -30,6 +30,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.common.profile_slot import active
 from repro.serving.budget import BudgetGovernor
 from repro.serving.queue import DONE, AdmissionQueue, Request
 from repro.serving.telemetry import Telemetry
@@ -241,7 +242,17 @@ class MicroBatchScheduler:
         cache-served requests); cache-served outcome snapshots (charged
         the entry's ORIGINAL generation cost, so the adapter's cost head
         keeps training on real economics) are appended to ``outcomes``.
+        Runs as span ``repro.sched.cache_rung`` with the layer profiler
+        installed.
         """
+        prof = active()
+        if prof is None:
+            return self._cache_rung_body(batch, q_emb, lam, outcomes)
+        with prof.span("repro.sched.cache_rung", n=len(batch)):
+            return self._cache_rung_body(batch, q_emb, lam, outcomes)
+
+    def _cache_rung_body(self, batch, q_emb, lam, outcomes):
+        """Rung 0 itself (see :meth:`_cache_rung`)."""
         now = self.clock.now
         tracer = self.tracer
         cache = self.semcache
@@ -377,8 +388,18 @@ class MicroBatchScheduler:
         re-admit the request at the queue head with a forced next member
         (escalation), and only stop decisions finalize. Every leg's cost
         is charged to the budget governor as it happens, so the ledger
-        sees the cascade's cumulative spend.
+        sees the cascade's cumulative spend. With the layer profiler
+        installed the round runs as span ``repro.sched.round``
+        (:mod:`repro.obs.profiling`).
         """
+        prof = active()
+        if prof is None:
+            return self._dispatch()
+        with prof.span("repro.sched.round"):
+            return self._dispatch()
+
+    def _dispatch(self) -> List[Request]:
+        """The round itself (see :meth:`dispatch`)."""
         served: List[Request] = []
         tracer = self.tracer
         if self.slo_enforce and self.slo is not None and self.queue.depth:

@@ -1,5 +1,5 @@
 """Observability plane tests: trace recorder + validators, metrics
-registry + exporters, kernel profiler, and the traced serving scheduler
+registry + exporters, layer profiler, and the traced serving scheduler
 (single worker and shared-recorder multi-worker views).
 
 The determinism contract under test everywhere: virtual-clock timestamps
@@ -12,9 +12,10 @@ import json
 import numpy as np
 import pytest
 
+from repro.common import profile_slot
 from repro.kernels import ops as kops
 from repro.obs import (
-    KernelProfiler,
+    LayerProfiler,
     MetricsRegistry,
     MetricsServer,
     TraceRecorder,
@@ -348,8 +349,8 @@ class TestTracedScheduler:
 class TestKernelProfiler:
     def test_profiler_hooks_pairwise_l2(self):
         rec = TraceRecorder()
-        prof = KernelProfiler(tracer=rec)
-        kops.set_kernel_profiler(prof)
+        prof = LayerProfiler(tracer=rec)
+        profile_slot.install(prof)
         try:
             x = np.random.default_rng(0).normal(size=(8, 4)).astype(
                 np.float32)
@@ -357,37 +358,38 @@ class TestKernelProfiler:
                 np.float32)
             out = np.asarray(kops.pairwise_l2(x, c))
         finally:
-            kops.set_kernel_profiler(None)
+            profile_slot.install(None)
+        name = "repro.kernels.pairwise_l2"
         assert out.shape == (8, 3)
-        assert prof.calls["pairwise_l2"] == 1
-        assert prof.elements["pairwise_l2"] == 8
-        assert prof.hists["pairwise_l2"].count == 1
+        assert prof.calls[name] == 1
+        assert [(s[0], s[3]["n"]) for s in prof.spans] == [(name, 8)]
+        assert prof.hists[name].count == 1
         # The span is wall-clock: kernel category, excluded by default.
         kernel_events = [e for e in rec.events if e[1] == "kernel"]
         assert len(kernel_events) == 1
         det = rec.chrome_trace()["traceEvents"]
         assert not any(e.get("cat") == "kernel" for e in det)
-        summ = prof.summary()["pairwise_l2"]
+        summ = prof.summary()[name]
         assert summ["calls"] == 1 and summ["p50_us"] > 0
-        assert "pairwise_l2" in prof.report()
+        assert name in prof.report()
 
     def test_uninstalled_profiler_is_passthrough(self):
-        assert kops.get_kernel_profiler() is None
+        assert profile_slot.active() is None
         x = np.zeros((4, 4), np.float32)
         c = np.zeros((2, 4), np.float32)
         assert np.asarray(kops.pairwise_l2(x, c)).shape == (4, 2)
 
     def test_register_metrics(self):
-        prof = KernelProfiler()
-        with prof.annotate("router_xattn_pool", batch=64):
+        prof = LayerProfiler()
+        with prof.span("repro.kernels.router_xattn_pool", n=64):
             pass
         reg = MetricsRegistry()
         prof.register_metrics(reg)
         snap = reg.snapshot()   # wall metrics: full snapshot only
-        assert snap['kernel_calls_total{op="router_xattn_pool"}'][
-            "value"] == 1
-        assert snap['kernel_elements_total{op="router_xattn_pool"}'][
-            "value"] == 64
+        key = '{span="repro.kernels.router_xattn_pool"}'
+        assert snap["profile_calls_total" + key]["value"] == 1
+        assert snap["profile_compiles_total" + key]["value"] == 0
+        assert snap["profile_latency_us" + key]["count"] == 1
         assert reg.snapshot(deterministic=True) == {}
 
 
