@@ -17,6 +17,7 @@ Three entry points:
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Dict, Optional, Tuple
 
@@ -39,6 +40,9 @@ from repro.models.layers import (
 from repro.models.sharding_ctx import shard
 
 LOSS_SEQ_CHUNK = 512
+# Decode caches are allocated at the next multiple of this many slots
+# above prompt + new tokens (see greedy_generate).
+CACHE_BUCKET = 256
 
 
 # ---------------------------------------------------------------------------
@@ -487,61 +491,85 @@ def apply_lm_decode(cfg, params, token, caches, pos):
 
 def greedy_generate(cfg, params, prompt, max_new: int, media=None,
                     dtype=jnp.float32, attn_mask=None):
-    """Simple greedy decoding loop for the examples (not perf-critical).
+    """Greedy decoding: eager prefill, then :func:`decode_loop`.
 
     ``attn_mask`` (B, S) bool marks real prompt tokens of a left-padded
     batch so every pool member's output — attention, SSM, xLSTM, and MoE
     alike — is invariant to micro-batch composition (see serving engine
     ``pad_prompts`` and tests/test_masked_prefill.py).
 
+    The decode caches hold :func:`cache_len` slots, the bucket of
+    ``S + max_new``: slots past the last written position stay empty
+    (``slot_pos`` -1) and are masked, so the tokens are those of a cache
+    of exactly ``S + max_new``, and calls whose prompt lengths share a
+    bucket reuse one compiled decode loop.
+
     With the layer profiler installed (:mod:`repro.common.profile_slot`)
-    the prefill runs as span ``repro.lm.prefill``, closed once the first
-    token is ready, and the decode steps as ``repro.lm.decode``, closed
-    once the last token is ready, each step inside it a
-    ``repro.lm.decode_step`` (arg ``i``, no sync). The tokens are the
+    the prefill runs as span ``repro.lm.prefill`` (``n``, ``length``),
+    closed once the first token is ready, and the decode loop as
+    ``repro.lm.decode`` (``n``, ``steps``, ``cache_len``), closed once the
+    last token is ready; a compile of the loop is charged to the latter.
+    Inside it ``repro.lm.decode_step`` (``steps``) is the wait for the
+    loop's steps on the device, after its dispatch. The tokens are the
     same either way.
     """
     prof = active()
-    if prof is not None:
-        return _greedy_generate_profiled(prof, cfg, params, prompt, max_new,
-                                         media, dtype, attn_mask)
-    s = prompt.shape[1]
-    tok, caches = _first_token(cfg, params, prompt, max_new, media, dtype,
-                               attn_mask)
-    out = [tok]
-    for i in range(max_new - 1):
-        tok, caches = _next_token(cfg, params, tok, caches, s + i)
-        out.append(tok)
-    return jnp.concatenate(out, axis=1)
-
-
-def _first_token(cfg, params, prompt, max_new, media, dtype, attn_mask):
     b, s = prompt.shape
-    caches = init_caches(cfg, b, s + max_new, dtype)
-    logits, caches = apply_lm_prefill(cfg, params, prompt, caches, media,
-                                      attn_mask=attn_mask)
-    tok = jnp.argmax(logits[:, -1, : cfg.vocab_size], axis=-1)[:, None]
-    return tok, caches
+    length = cache_len(s, max_new)
+    with _span(prof, "repro.lm.prefill", n=b, length=s):
+        caches = init_caches(cfg, b, length, dtype)
+        logits, caches = apply_lm_prefill(cfg, params, prompt, caches, media,
+                                          attn_mask=attn_mask)
+        tok = _greedy_token(cfg, logits)
+        if prof is not None:
+            jax.block_until_ready(tok)
+    with _span(prof, "repro.lm.decode", n=b, steps=max_new - 1,
+               cache_len=length):
+        rest, _ = decode_loop(params, tok, caches, s, cfg=cfg,
+                              max_new=max_new)
+        if prof is not None:
+            with prof.span("repro.lm.decode_step", steps=max_new - 1):
+                jax.block_until_ready(rest)
+    return jnp.concatenate([tok, rest], axis=1)
 
 
-def _next_token(cfg, params, tok, caches, pos: int):
-    logits, caches = apply_lm_decode(cfg, params, tok, caches, jnp.int32(pos))
-    tok = jnp.argmax(logits[:, -1, : cfg.vocab_size], axis=-1)[:, None]
-    return tok, caches
+def cache_len(s: int, max_new: int) -> int:
+    """Decode-cache slots for an ``s``-token prompt and ``max_new`` new
+    tokens: ``s + max_new`` rounded up to a multiple of
+    :data:`CACHE_BUCKET`."""
+    return -(-(s + max_new) // CACHE_BUCKET) * CACHE_BUCKET
 
 
-def _greedy_generate_profiled(prof, cfg, params, prompt, max_new, media,
-                              dtype, attn_mask):
-    b, s = prompt.shape
-    with prof.span("repro.lm.prefill", n=b, length=s):
-        tok, caches = _first_token(cfg, params, prompt, max_new, media,
-                                   dtype, attn_mask)
-        jax.block_until_ready(tok)
-    out = [tok]
-    with prof.span("repro.lm.decode", n=b, steps=max_new - 1):
-        for i in range(max_new - 1):
-            with prof.span("repro.lm.decode_step", i=i):
-                tok, caches = _next_token(cfg, params, tok, caches, s + i)
-            out.append(tok)
-        jax.block_until_ready(tok)
-    return jnp.concatenate(out, axis=1)
+@functools.partial(jax.jit, static_argnames=("cfg", "max_new"),
+                   donate_argnames=("caches",))
+def decode_loop(params, first_tok, caches, s, *, cfg: ArchConfig,
+                max_new: int):
+    """The ``max_new - 1`` greedy steps after ``first_tok`` (B, 1), as one
+    program per (config, ``max_new``, batch, cache length).
+
+    ``s``, the prompt length and so the first decode position, is traced,
+    so prompts of any length in one cache bucket share the program.
+    ``params`` is an argument, never a constant of the program. The caches
+    are donated and come back updated in place (returning them is what
+    lets the donation alias them), so one copy is live. Returns
+    ``(tokens (B, max_new - 1), caches)``.
+    """
+    def step(carry, i):
+        tok, caches = carry
+        logits, caches = apply_lm_decode(cfg, params, tok, caches, s + i)
+        tok = _greedy_token(cfg, logits)
+        return (tok, caches), tok[:, 0]
+
+    (_, caches), toks = jax.lax.scan(
+        step, (first_tok, caches), jnp.arange(max_new - 1, dtype=jnp.int32))
+    return toks.T, caches
+
+
+def _greedy_token(cfg, logits):
+    return jnp.argmax(logits[:, -1, : cfg.vocab_size], axis=-1)[:, None]
+
+
+def _span(prof, name, **args):
+    if prof is None:
+        return contextlib.nullcontext()
+    return prof.span(name, **args)

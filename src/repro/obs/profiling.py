@@ -16,9 +16,11 @@ span                            where (args)
                                 ``max_new``)
 ``repro.lm.prefill``            ``greedy_generate``: prompt to first token,
                                 closed once that token is ready
-``repro.lm.decode``             ``greedy_generate``: every decode step,
+``repro.lm.decode``             ``greedy_generate``: the compiled decode
+                                loop (``n``, ``steps``, ``cache_len``),
                                 closed once the last token is ready
-``repro.lm.decode_step``        one decode step's dispatch (``i``)
+``repro.lm.decode_step``        inside it, the wait for the loop's steps
+                                on the device (``steps``)
 ``repro.kernels.<kernel>``      ``router_xattn_pool``, ``pairwise_l2``
                                 (``n``), closed once the result is ready
 ==============================  ==========================================

@@ -3,8 +3,9 @@
 The TPU compiler refuses what interpret mode accepts: tiles not aligned
 to the hardware, kernels over their fast-memory budget, programs larger
 than the chip's memory. These tests compile the serving path's kernels at
-their serving widths, and qwen3-0.6b's decode step at its published
-widths, for a described v5e. The topology is described inside a fixture,
+their serving widths, qwen3-0.6b's decode step and both benchmark
+members' compiled decode loops at their published widths, for a
+described v5e. The topology is described inside a fixture,
 so no module import loads the TPU library.
 """
 import functools
@@ -113,4 +114,34 @@ def test_qwen3_decode_step_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 3e9      # ~0.75 B float32 params
+    assert total < V5E_HBM_BYTES, total
+
+
+@pytest.mark.parametrize("name", ["qwen3-0.6b", "granite-moe-1b-a400m"])
+def test_decode_loop_fits_one_chip(one_chip, name):
+    """The compiled decode loop at published widths (float32 parameters and
+    caches), a generate micro-batch of 8 at the top cache bucket of the
+    benchmark's prompts (899 + 8 tokens, 1024 slots), 7 steps after the
+    first token: the donated caches alias the returned ones, so one copy
+    is live, and the program fits one v5e's 16 GiB."""
+    cfg = get_config(name)
+    batch, max_new = 8, 8
+    max_len = lm_mod.cache_len(899, max_new)
+    assert max_len == 1024
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, one_chip, a.dtype), tree)
+
+    params = on_chip(lm_mod.abstract_params(cfg))
+    caches = on_chip(lm_mod.abstract_caches(cfg, batch, max_len))
+    compiled = lm_mod.decode_loop.lower(
+        params, _sds((batch, 1), one_chip, jnp.int32), caches,
+        _sds((), one_chip, jnp.int32), cfg=cfg, max_new=max_new).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(caches))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 3e9      # float32 parameters
     assert total < V5E_HBM_BYTES, total
