@@ -15,8 +15,10 @@ timed path produced is held against the plain references:
   gap.<member>    for a sample of the generated requests drawn from the
                   seed (the longest prompt always in it): the widest gap
                   by which a served token's logit lies below the best
-                  logit of ``reference/decoder.py`` at that position,
-                  teacher-forced over prompt plus served tokens
+                  logit of the member's reference at that position
+                  (``reference/<module>.py``, the module its file names
+                  under "reference"), teacher-forced over prompt plus
+                  served tokens
   bad_len         completed requests that did not get ``max_new`` tokens
   cache_mismatch  cache-served requests whose answer is not the answer
                   generated earlier for a text within the cache radius
@@ -35,7 +37,7 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
-from bench.reference import decoder as ref_decoder
+from bench.reference import for_member
 from bench.reference import router as ref_router
 
 CONTROLS = ("bfloat16", "float8_e4m3fn", "token_altered")
@@ -154,6 +156,7 @@ def _gap_numbers(rows, members: Sequence[dict], weights, control):
         mine = [(p, o) for m, p, o in rows if m == mi]
         if not mine:
             continue
+        ref = for_member(member)
         seq = _round_up(max(len(p) + len(o) for p, o in mine), 128)
         steps = max(len(o) for _, o in mine)
         gaps = []
@@ -166,11 +169,11 @@ def _gap_numbers(rows, members: Sequence[dict], weights, control):
                 toks[i, :len(full)] = full
                 read[i] = len(p) - 1 + np.minimum(np.arange(steps),
                                                   len(o) - 1)
-            want = np.asarray(ref_decoder.logits_at(
+            want = np.asarray(ref.logits_at(
                 member, weights[mi], jnp.asarray(toks), jnp.asarray(read)),
                 np.float64)
             if low_dtype is not None:
-                low = np.asarray(ref_decoder.logits_at(
+                low = np.asarray(ref.logits_at(
                     member, weights[mi], jnp.asarray(toks),
                     jnp.asarray(read), dtype=low_dtype))
             for i, (p, o) in enumerate(chunk):

@@ -38,6 +38,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from bench import reference
 from bench import traffic as traffic_mod
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -45,6 +46,8 @@ BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 # count as failed (a run has to end within its time limit).
 DRAIN_LIMIT_S = 120.0
 CLOSED_LOOP_BLOCKS = 8
+# Floating-point types a configuration may serve its members' weights in.
+WEIGHT_DTYPES = ("float32", "bfloat16")
 
 
 def bench_dir(root: str) -> str:
@@ -247,13 +250,15 @@ def build(spec: CellSpec, seed: int, *, full_width: bool = True,
 
     config = spec.config
     mix = {**spec.mix, **(mix_overrides or {})}
+    dtype = weights_dtype(config)
+    refs = [reference.for_member(m) for m in spec.members]
     cfgs, weights, pool = [], [], []
     for i, m in enumerate(spec.members):
         name = m["program_config"]
         cfg = dataclasses.replace(
             (get_config if full_width else get_smoke_config)(name),
             **m.get("program_overrides", {}))
-        params = make_member_params(cfg, seed, i)
+        params = make_member_params(cfg, seed, i, dtype)
         cfgs.append(cfg)
         weights.append(params)
         pool.append(PoolMember(name=name, cfg=cfg, params=params,
@@ -309,8 +314,8 @@ def build(spec: CellSpec, seed: int, *, full_width: bool = True,
     else:
         drafts = traffic_mod.closed_loop(mix, texts, seed,
                                          CLOSED_LOOP_BLOCKS, vocab)
-    ref_members = [m if full_width else _smoke_member(m, c)
-                   for m, c in zip(spec.members, cfgs)]
+    ref_members = [m if full_width else ref.smoke_member(m, c)
+                   for ref, m, c in zip(refs, spec.members, cfgs)]
     return types.SimpleNamespace(
         spec=spec, mix=mix, seed=seed, engine=engine, semcache=semcache,
         cascade=cascade, drafts=drafts, weights=weights,
@@ -318,37 +323,24 @@ def build(spec: CellSpec, seed: int, *, full_width: bool = True,
         train_texts=radius_texts, cfgs=cfgs)
 
 
-def _smoke_member(member: dict, cfg) -> dict:
-    """The member's reference sizes at the program's reduced smoke widths
-    (CPU rehearsal only)."""
-    conf = dict(member["config"])
-    conf.update(hidden_size=cfg.d_model, num_hidden_layers=cfg.n_layers,
-                num_attention_heads=cfg.n_heads,
-                num_key_value_heads=cfg.n_kv_heads,
-                head_dim=cfg.resolved_head_dim, vocab_size=cfg.vocab_size,
-                intermediate_size=cfg.d_ff_expert or cfg.d_ff)
-    if cfg.n_experts:
-        conf.update(num_local_experts=cfg.n_experts,
-                    num_experts_per_tok=cfg.top_k)
-    return {**member, "config": conf}
+def weights_dtype(config: dict):
+    """The type the members' weights are made and served in: the
+    configuration's ``"dtype"``, one of ``WEIGHT_DTYPES``."""
+    import jax.numpy as jnp
+
+    name = config.get("dtype")
+    if name not in WEIGHT_DTYPES:
+        raise ValueError(f"configuration {config.get('name')!r}: \"dtype\" "
+                         f"{name!r} is none of {', '.join(WEIGHT_DTYPES)}")
+    return jnp.dtype(name)
 
 
 def check_sizes(built) -> None:
-    """At published widths the program's configs must hold the sizes the
-    members' files state (the reference runs from the files)."""
+    """The program's configs must hold the sizes the members' files
+    state (the reference runs from the files); each member's reference
+    module compares them."""
     for m, cfg in zip(built.ref_members, built.cfgs):
-        c = m["config"]
-        got = dict(hidden_size=cfg.d_model, num_hidden_layers=cfg.n_layers,
-                   num_attention_heads=cfg.n_heads,
-                   num_key_value_heads=cfg.n_kv_heads,
-                   vocab_size=cfg.vocab_size,
-                   head_dim=cfg.resolved_head_dim)
-        want = dict(c, head_dim=c.get("head_dim")
-                    or c["hidden_size"] // c["num_attention_heads"])
-        bad = {k: (v, want[k]) for k, v in got.items() if want[k] != v}
-        if bad:
-            raise ValueError(f"{m['name']}: program sizes differ from the "
-                             f"published ones: {bad}")
+        reference.for_member(m).check_sizes(m, cfg)
 
 
 def warm_up(built) -> None:
@@ -531,21 +523,28 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     under ``"control"`` (limit setting, see ``bench/limits.py``; the
     benchmark's runs read none). A sweep
     (``bench/sweep.py``) passes ``check=False`` to skip the comparison and
-    reads the window through ``on_window``."""
+    reads the window through ``on_window``.
+
+    With ``trace`` the program's ``LayerProfiler`` is installed from the
+    warm-up's end to the window's close, and its spans go on the run
+    record as ``program_spans`` on the window's clock; on a TPU the window
+    is also profiled (``jax.profiler``) and its trace reduced. Without
+    ``trace`` nothing is installed."""
     import jax
     from jax import monitoring
 
-    from bench import correct
-    from bench import trace_reduce
+    from bench import correct, program_spans, trace_reduce
+    from repro.common import profile_slot
     from repro.common.compile_cache import enable_compile_cache
+    from repro.obs.profiling import LayerProfiler
     from repro.serving import MicroBatchScheduler, SchedulerConfig
 
     enable_compile_cache()
+    dev = jax.devices()[0]
     spec = load_cell(root, workload)
     built = build(spec, seed, full_width=full_width, seconds=seconds,
                   mix_overrides=mix_overrides)
-    if full_width:
-        check_sizes(built)
+    check_sizes(built)
     warm_up(built)
 
     clock = WallClock()
@@ -557,33 +556,41 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         semcache=built.semcache)
     trace_dir = os.path.join(root, "bench_out", "trace",
                              f"{workload}-{seed}")
+    # Only a TPU's trace has device planes to reduce (trace_reduce); a
+    # rehearsal on the CPU records the program's spans alone.
+    device_trace = trace and dev.platform == "tpu"
+    prof = LayerProfiler() if trace else None
     window_span = None
-    if trace:
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        window_span = rec.span("window")
-        window_span.__enter__()
 
     def on_close():
         if window_span is not None:
             window_span.__exit__(None, None, None)
             jax.profiler.stop_trace()
+        if prof is not None:
+            profile_slot.install(None)
 
-    monitoring.register_event_duration_secs_listener(rec.on_duration)
-    setup_s = time.perf_counter() - t_start
-    try:
-        with no_cache_writes():
-            if built.mix["loop"] == "open":
-                win = drive_open(sched, clock, rec, built.drafts, seconds,
-                                 on_close)
-            else:
-                win = drive_closed(sched, clock, rec, built.drafts,
-                                   int(built.mix["clients"]), seconds,
-                                   on_close)
-    finally:
-        monitoring.unregister_event_duration_listener(rec.on_duration)
+    with contextlib.ExitStack() as stack:
+        if prof is not None:
+            profile_slot.install(prof)
+            stack.callback(profile_slot.install, None)
+        if device_trace:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+            window_span = rec.span("window")
+            window_span.__enter__()
+        monitoring.register_event_duration_secs_listener(rec.on_duration)
+        stack.callback(monitoring.unregister_event_duration_listener,
+                       rec.on_duration)
+        setup_s = time.perf_counter() - t_start
+        stack.enter_context(no_cache_writes())
+        if built.mix["loop"] == "open":
+            win = drive_open(sched, clock, rec, built.drafts, seconds,
+                             on_close)
+        else:
+            win = drive_closed(sched, clock, rec, built.drafts,
+                               int(built.mix["clients"]), seconds, on_close)
     if on_window is not None:
         on_window(win)
     peak = peak_bytes()
@@ -605,18 +612,22 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     control_numbers = {c: compare(control=c) for c in controls}
     check_s = time.perf_counter() - t_check
 
-    reduced = None
-    if trace:
-        reduced = trace_reduce.reduce(trace_reduce.find_xplane(trace_dir),
-                                      window_s=seconds)
+    reduced = spans_line = None
+    if device_trace:
+        xplane = trace_reduce.find_xplane(trace_dir)
+        reduced = trace_reduce.reduce(xplane, window_s=seconds)
+        spans_line = program_spans.line(
+            program_spans.collect(xplane, window_s=seconds))
     run = types.SimpleNamespace(
         spec=spec, seed=seed, seconds=seconds, setup_s=setup_s, window=win,
         rec=rec, cache=cache_report, trace=reduced, members=built.ref_members,
         peaks=peaks, router_shapes=dict(
             d_query=built.router_ref["quality"]["wq"].shape[0],
             latent=built.router_ref["quality"]["wq"].shape[1],
-            members=built.router_ref["model_emb"].shape[0]))
-    dev = jax.devices()[0]
+            members=built.router_ref["model_emb"].shape[0]),
+        program_spans=None if prof is None else [
+            (name, t0 - clock.t0, t1 - clock.t0, args)
+            for name, t0, t1, args in prof.spans])
     metrics = {}
     for m in (spec.per_layer if trace else spec.end_to_end):
         value = load_reader(root, m["name"])(run)
@@ -641,5 +652,6 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     out["_lines"] = ([f"bench: setup {setup_s:.1f} s, window {seconds:g} s, "
                       f"drain {win.t_end - win.t_close:.1f} s, check "
                       f"{check_s:.1f} s; {window_counts(rec, win)}"]
+                     + ([spans_line] if spans_line is not None else [])
                      + correct.summary_lines(numbers))
     return out

@@ -10,9 +10,10 @@ compile to the innermost span open when it ran (``compiles`` and
 Two readings:
 
 - a run record's ``program_spans``, the profiler's spans as ``(name, t0,
-  t1, args)`` on the window's clock: the per-layer numbers below read the
-  spans that end inside the window, and return None where a run has none
-  (a program without the profiler, or a run that did not install it);
+  t1, args)`` on the window's clock, which ``harness.run_cell`` records in
+  a traced run: the per-layer numbers below read the spans that end
+  inside the window, and return None where a run has none (a program
+  without the profiler, or an untraced run);
 - :func:`collect`, the ``repro.*`` annotations of a profiler trace beside
   its device operations: every idle gap of the device named by the
   innermost program span over it (``compile in <span>`` where compiles
@@ -73,13 +74,12 @@ def compile_pct(run, name: str) -> Optional[float]:
     return 100.0 * compile_s / secs
 
 
-def readings(run) -> Dict[str, Optional[float]]:
-    """The four per-layer numbers of one run, by metric stem (a cell's
-    metric adds ``.open`` or ``.batch``)."""
-    return {"prefill_s_per_call": prefill_s_per_call(run),
-            "decode_s_per_step": decode_s_per_step(run),
-            "generate_compile_pct": compile_pct(run, GENERATE),
-            "score_compile_pct": compile_pct(run, SCORE)}
+def generate_compile_pct(run) -> Optional[float]:
+    return compile_pct(run, GENERATE)
+
+
+def score_compile_pct(run) -> Optional[float]:
+    return compile_pct(run, SCORE)
 
 
 # -- the same spans in a profiler trace --------------------------------------

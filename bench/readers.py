@@ -11,6 +11,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from bench.reference import for_member
+
 
 def window_gens(run) -> List[dict]:
     """Generate calls that ended inside the window."""
@@ -41,36 +43,20 @@ def percentile(values, q: float) -> Optional[float]:
     return float(np.percentile(values, q))
 
 
-def n_active(member: dict) -> int:
-    """Parameters that multiply each token's activations, from the
-    published config: attention projections, the dense FFN or the router
-    plus ``num_experts_per_tok`` experts, and the LM head. The token
-    table is a lookup and not counted."""
-    c = member["config"]
-    d, heads, kv = (c["hidden_size"], c["num_attention_heads"],
-                    c["num_key_value_heads"])
-    hd = c.get("head_dim") or d // heads
-    attn = d * heads * hd + 2 * d * kv * hd + heads * hd * d
-    if c.get("num_local_experts"):
-        ffn = (d * c["num_local_experts"]
-               + c["num_experts_per_tok"] * 3 * d * c["intermediate_size"])
-    else:
-        ffn = 3 * d * c["intermediate_size"]
-    return c["num_hidden_layers"] * (attn + ffn) + d * c["vocab_size"]
-
-
 def generate_seconds(run) -> float:
     return sum(g["t1"] - g["t0"] for g in window_gens(run))
 
 
 def step_mfu_pct(run) -> Optional[float]:
     """2 * N_active * (prompt + generated tokens) of every generate call
-    in the window, over the calls' host-timed seconds at the chip's peak."""
+    in the window, over the calls' host-timed seconds at the chip's peak;
+    N_active from each member's reference module."""
     gens = window_gens(run)
     secs = generate_seconds(run)
     if not gens or secs <= 0:
         return None
-    flops = sum(2.0 * n_active(run.members[g["member"]])
+    active = [for_member(m).n_active(m) for m in run.members]
+    flops = sum(2.0 * active[g["member"]]
                 * (sum(len(p) for p in g["prompts"])
                    + sum(len(o) for o in g["outs"])) for g in gens)
     return 100.0 * flops / (secs * run.peaks["flops_per_s"])

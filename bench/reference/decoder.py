@@ -18,6 +18,9 @@ in float8 with a scale per tensor and the rest in bfloat16.
 Parameters are read in the layout the benchmark's weights are made in
 (``bench/weights.py``): ``embedding/{table,head}``, ``final_norm/scale``
 and one stacked layer block under ``pattern``.
+
+A member reference (``"reference": "decoder"``): it keeps the contract of
+``bench/reference/__init__.py``.
 """
 from __future__ import annotations
 
@@ -126,3 +129,53 @@ def logits_at(member: dict, params, tokens, read_at, dtype=jnp.float32):
             return _logits_at(params, tokens, read_at, member=key,
                               dtype=jnp.float32)
     return _logits_at(params, tokens, read_at, member=key, dtype=dtype)
+
+
+def n_active(member: dict) -> int:
+    """Parameters that multiply each token's activations, from the
+    published config: attention projections, the dense FFN or the router
+    plus ``num_experts_per_tok`` experts, and the LM head. The token
+    table is a lookup and not counted."""
+    c = member["config"]
+    d, heads, kv = (c["hidden_size"], c["num_attention_heads"],
+                    c["num_key_value_heads"])
+    hd = c.get("head_dim") or d // heads
+    attn = d * heads * hd + 2 * d * kv * hd + heads * hd * d
+    if c.get("num_local_experts"):
+        ffn = (d * c["num_local_experts"]
+               + c["num_experts_per_tok"] * 3 * d * c["intermediate_size"])
+    else:
+        ffn = 3 * d * c["intermediate_size"]
+    return c["num_hidden_layers"] * (attn + ffn) + d * c["vocab_size"]
+
+
+def check_sizes(member: dict, cfg) -> None:
+    """The program's config must hold the sizes the member's file states
+    (the reference runs from the file)."""
+    c = member["config"]
+    got = dict(hidden_size=cfg.d_model, num_hidden_layers=cfg.n_layers,
+               num_attention_heads=cfg.n_heads,
+               num_key_value_heads=cfg.n_kv_heads,
+               vocab_size=cfg.vocab_size,
+               head_dim=cfg.resolved_head_dim)
+    want = dict(c, head_dim=c.get("head_dim")
+                or c["hidden_size"] // c["num_attention_heads"])
+    bad = {k: (v, want[k]) for k, v in got.items() if want[k] != v}
+    if bad:
+        raise ValueError(f"{member['name']}: program sizes differ from the "
+                         f"published ones: {bad}")
+
+
+def smoke_member(member: dict, cfg) -> dict:
+    """The member's reference sizes at the program's reduced smoke widths
+    (CPU rehearsal only)."""
+    conf = dict(member["config"])
+    conf.update(hidden_size=cfg.d_model, num_hidden_layers=cfg.n_layers,
+                num_attention_heads=cfg.n_heads,
+                num_key_value_heads=cfg.n_kv_heads,
+                head_dim=cfg.resolved_head_dim, vocab_size=cfg.vocab_size,
+                intermediate_size=cfg.d_ff_expert or cfg.d_ff)
+    if cfg.n_experts:
+        conf.update(num_local_experts=cfg.n_experts,
+                    num_experts_per_tok=cfg.top_k)
+    return {**member, "config": conf}

@@ -9,7 +9,8 @@ import time
 import numpy as np
 import pytest
 
-from bench import correct, harness, readers
+from bench import correct, harness
+from bench.reference import for_member
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -32,8 +33,35 @@ def _run(mix=MIX, seconds=SECONDS, **kw):
 
 
 @pytest.fixture(scope="module")
-def rehearsal():
-    return _run(controls=["bfloat16"])
+def drive():
+    """The rehearsal, and the profiler installed at each generate call of
+    its warm-up and drive."""
+    from repro.common import profile_slot
+    from repro.serving import engine
+
+    installed = []
+    real = engine.RoutedEngine.generate_member
+
+    def generate_member(self, *args, **kw):
+        installed.append(profile_slot.active())
+        return real(self, *args, **kw)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine.RoutedEngine, "generate_member", generate_member)
+        res, win = _run(controls=["bfloat16"])
+    return res, win, installed
+
+
+@pytest.fixture(scope="module")
+def rehearsal(drive):
+    return drive[:2]
+
+
+def test_an_untraced_run_installs_no_profiler(drive):
+    res, win, installed = drive
+    # One warm-up call per member, then the window's calls.
+    assert len(installed) > 2
+    assert set(installed) == {None}
 
 
 def test_result_has_the_result_line_keys(rehearsal):
@@ -79,13 +107,13 @@ def test_active_parameters_follow_the_published_sizes():
     qwen, granite = spec.members
     # Qwen3-0.6B: 28 x (attention 1024x(2048+1024+1024) + 2048x1024, SwiGLU
     # 3x1024x3072) + LM head 1024 x 151936.
-    assert readers.n_active(qwen) == 28 * (
+    assert for_member(qwen).n_active(qwen) == 28 * (
         1024 * 2048 + 2 * 1024 * 1024 + 2048 * 1024
         + 3 * 1024 * 3072) + 1024 * 151936
     # granite: 24 x (attention: 16 query heads of 64 and 8 key/value
     # heads, so 3 x 1024^2 + router 1024x32 + 8 experts of 3 x 1024 x 512)
     # + head 1024 x 49155.
-    assert readers.n_active(granite) == 24 * (
+    assert for_member(granite).n_active(granite) == 24 * (
         3 * 1024 * 1024 + 1024 * 32 + 8 * 3 * 1024 * 512) + 1024 * 49155
 
 

@@ -1,20 +1,26 @@
 """The program's layer spans as the benchmark reads them: the per-layer
 numbers on a synthetic run record, gap naming on synthetic intervals, a
 recorded v5e trace with ``repro.*`` spans (``record_program_trace.py``),
-and a CPU rehearsal of ``profile_cell.profiled_run``."""
+and a CPU rehearsal of a traced ``harness.run_cell``, which installs the
+profiler."""
 import os
 import time
 import types
 
+import jax
 import pytest
 
-from bench import profile_cell
+from bench import harness
 from bench import program_spans as ps
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                     "program_trace.xplane.pb")
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+
+
+def _reader(metric):
+    return harness.load_reader(ROOT, metric)
 
 
 def _record(spans, close=10.0):
@@ -62,15 +68,16 @@ EXPECTED = {
 @pytest.mark.parametrize("stem", sorted(EXPECTED))
 def test_readings_on_a_synthetic_record(stem, cell):
     # A cell's metric is <stem>.<cell>; both read the same arithmetic.
-    got = ps.readings(_record(SPANS))
-    assert got[stem] == pytest.approx(EXPECTED[stem])
+    got = _reader(f"{stem}.{cell}")(_record(SPANS))
+    assert got == pytest.approx(EXPECTED[stem])
 
 
 def test_no_spans_read_nothing():
     # A program without the profiler, or a run that did not install it.
     for rec in (types.SimpleNamespace(window=types.SimpleNamespace(
             t_close=10.0)), _record([])):
-        assert set(ps.readings(rec).values()) == {None}
+        assert {_reader(f"{stem}.{cell}")(rec) for stem in EXPECTED
+                for cell in ("open", "batch")} == {None}
 
 
 def test_gaps_go_to_the_innermost_span_and_to_its_compiles():
@@ -127,23 +134,31 @@ def test_recorded_idle_time_names_program_spans_and_compiles(recorded):
     assert recorded.idle["host"] == pytest.approx(0.2, abs=0.05)
 
 
-def test_profiled_rehearsal_reads_every_layer_and_stays_correct():
-    res, rec = profile_cell.profiled_run(
-        ROOT, "pool2-route.mcq", 2**33 + 2**31 + 78, 4.0, trace=False,
+def test_profiled_rehearsal_reads_every_layer_and_stays_correct(
+        reader_runs):
+    # Compile anew whatever an earlier test in this process compiled, so
+    # the window's compile shares read as in a fresh process.
+    jax.clear_caches()
+    res = harness.run_cell(
+        ROOT, "pool2-route.mcq", 2**33 + 2**31 + 78, 4.0, True,
         t_start=time.perf_counter(),
         peaks={"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
         full_width=False, mix_overrides={"rate_per_s": 2.0})
     # Tokens made with the profiler on still match the reference.
     assert res["correct"] is True
-    assert set(res["program"]["readings"]) == set(EXPECTED)
+    run = reader_runs[-1]
+    assert run.program_spans
     # At smoke widths on the CPU a generate call outlasts the window: read
-    # every span of the drive.
-    whole = _record(rec.program_spans, close=rec.window.t_end)
-    readings = ps.readings(whole)
+    # every span of the drive, through the eight metric readers.
+    whole = _record(run.program_spans, close=run.window.t_end)
+    readings = {f"{stem}.{cell}": _reader(f"{stem}.{cell}")(whole)
+                for stem in EXPECTED for cell in ("open", "batch")}
     assert all(v is not None and v > 0 for v in readings.values()), \
         readings
-    assert readings["generate_compile_pct"] <= 100
-    names = {s[0] for s in rec.program_spans}
+    assert readings["generate_compile_pct.open"] <= 100
+    assert all(readings[f"{stem}.open"] == readings[f"{stem}.batch"]
+               for stem in EXPECTED)
+    names = {s[0] for s in run.program_spans}
     assert {"repro.sched.round", "repro.engine.embed", "repro.engine.score",
             "repro.engine.generate", "repro.lm.prefill", "repro.lm.decode",
             "repro.lm.decode_step"} <= names

@@ -13,14 +13,16 @@ A mix (``bench/traffic/<name>.json``) gives the loop and the shapes:
   repeat_frac   share of arrivals that repeat a text of the hot set
   hot_set       size of that hot set; picks within it are Zipf(zipf_s)
 
-Every seed gets the same work in another order. The texts, each text's
-prompt length and the inter-arrival gaps are fixed, drawn once from
-``SHAPE_SEED``; ``--seed`` only permutes them and draws the prompt ids.
-So two seeds route the same requests to each member with the same
-lengths, and differ in which request comes when. Prompt ids are drawn
-from the text and the seed, so a repeated text carries the same tokens.
-Closed-loop requests come in blocks of ``clients``: each block holds the
-same texts and lengths for every seed.
+Every seed gets the same work. The texts, each text's prompt length,
+the arrival times and the order of the requests are fixed, drawn once
+from ``SHAPE_SEED``; ``--seed`` draws the prompt ids (and, in the
+harness, the members' weights). The order is fixed too because the
+program under test compiles its prefill for every new padded batch
+shape: which requests share a micro-batch decides which shapes compile,
+so a seed that reordered the requests would change the work, not only
+the data. Prompt ids are drawn from the text and the seed, so a repeated
+text carries the same tokens. Closed-loop requests come in blocks of
+``clients``, the same texts and lengths in the same order for every seed.
 
 The arrival process is the Poisson/Zipf hot-set shape of the program's
 ``serving/traffic.py`` (``poisson`` and ``neardup``), copied here with
@@ -118,12 +120,10 @@ def open_loop(mix: dict, texts: Sequence[str], seed: int, seconds: float,
     length_of = dict(zip(pool, shape.permutation(_lengths(len(pool), lo,
                                                           hi))))
 
-    rng = np.random.default_rng(_seed_words(seed))
-    gaps = rng.permutation(gaps)
     # The gaps fill the window exactly, so every seed offers n requests
     # in [0, seconds); the last one is due half a mean gap before the end.
     due = np.cumsum(gaps) / gaps.sum() * (seconds - 0.5 * seconds / n)
-    order = rng.permutation(n)
+    order = shape.permutation(n)
     return [Draft(text=chosen[i],
                   prompt=prompt_ids(chosen[i], int(length_of[chosen[i]]),
                                     seed, vocab),
@@ -134,21 +134,21 @@ def open_loop(mix: dict, texts: Sequence[str], seed: int, seconds: float,
 def closed_loop(mix: dict, texts: Sequence[str], seed: int, n_blocks: int,
                 vocab: int) -> List[Draft]:
     """``n_blocks`` blocks of ``clients`` requests, offered in order as
-    earlier ones complete (``due_s`` is set when offered)."""
+    earlier ones complete (``due_s`` is set when offered); each block's
+    texts in corpus order, with lengths drawn from ``SHAPE_SEED``."""
     c = int(mix["clients"])
     if c * n_blocks > len(texts):
         raise ValueError(f"{c * n_blocks} distinct texts needed, corpus has "
                          f"{len(texts)}")
     lo, hi = mix["prompt_len"]
     shape = np.random.default_rng(SHAPE_SEED)
-    rng = np.random.default_rng(_seed_words(seed))
     out = []
     for b in range(n_blocks):
-        block = list(texts[b * c:(b + 1) * c])
+        block = texts[b * c:(b + 1) * c]
         block_lens = shape.permutation(_lengths(c, lo, hi))
-        for i in rng.permutation(c):
-            out.append(Draft(text=block[i],
-                             prompt=prompt_ids(block[i], int(block_lens[i]),
-                                               seed, vocab),
+        for text, length in zip(block, block_lens):
+            out.append(Draft(text=text,
+                             prompt=prompt_ids(text, int(length), seed,
+                                               vocab),
                              max_new=int(mix["max_new"]), due_s=0.0))
     return out
